@@ -621,6 +621,8 @@ def main() -> None:
         return
     quick = not args.full
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     from . import (decode_bench, estimator_bench, fig1_cdf, kernels_bench,
                    roofline, serving_bench, table1_grid, table2_noise,

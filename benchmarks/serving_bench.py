@@ -26,9 +26,10 @@ a traffic-serving deployment cares about:
    non-speculative and the warm cache must save replay steps, still with
    bit-identical tokens and zero recompiles,
  * a SCALING curve for the mesh-sharded scheduler step (DESIGN.md SS15):
-   goodput / p95 / occupancy at 1/2/4/8 virtual devices, one subprocess
-   per (data, model) mesh shape, with token parity vs solo generate() and
-   zero recompiles required at every shape (see ``_scaling``).
+   goodput / p95 / occupancy per (data, model) mesh shape that fits the
+   devices (1/2/4/8; on the CPU, 8 forced host devices in one child
+   process), with token parity vs solo generate() and zero recompiles
+   required at every shape (see ``_scaling``).
 
 Writes BENCH_serving.json; gated by ``benchmarks/run.py --check``.
 """
@@ -395,17 +396,18 @@ def _raw_speed(quick: bool):
     return spec, prefix
 
 
-def _scaling_child(data: int, model: int, quick: bool = True):
-    """One scaling-curve row. Runs in a SUBPROCESS whose environment sets
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` before jax is
-    imported (the parent process owns a single-device jax runtime).
+SCALING_SHAPES = ((1, 1), (2, 1), (4, 1), (8, 1), (2, 2))
+
+
+def _scaling_row(data: int, model: int, quick: bool = True) -> dict:
+    """One scaling-curve row over the first data*model devices.
 
     Builds a (data, model)-mesh engine with ``lanes_per_replica * data``
     slot lanes, warms the scheduler, serves a saturating all-at-once trace
     twice (best-of-2 goodput damps scheduler-noise on a shared host), and
     checks the two hard invariants per row: tokens bit-identical to a
     single-device solo ``generate()`` oracle, and zero retraces after
-    warmup. Emits one ``SCALING::{json}`` line on stdout for the parent.
+    warmup.
     """
     from repro.launch.mesh import make_serving_mesh
     from repro.serve import Scheduler, Server, trace_arrivals
@@ -458,17 +460,61 @@ def _scaling_child(data: int, model: int, quick: bool = True):
         "token_parity": bool(parity),
         "recompiles_after_warmup": int(recompiles),
     }
-    print("SCALING::" + json.dumps(row), flush=True)
+    print(f"  mesh data={data},model={model}: {row['tok_per_step']:.1f} "
+          f"tok/step ({row['goodput_tok_s']:.0f} tok/s wall), p95 "
+          f"{row['p95_token_ms']:.2f}ms, parity {row['token_parity']}, "
+          f"recompiles {row['recompiles_after_warmup']}", flush=True)
+    return row
+
+
+def _scaling_rows(quick: bool = True) -> list:
+    """Every scaling shape that fits this process's devices, in-process."""
+    n = jax.device_count()
+    return [_scaling_row(d, m, quick) for d, m in SCALING_SHAPES
+            if d * m <= n]
+
+
+def _forced_cpu_rows(quick: bool) -> list:
+    """The CPU stand-in for a multi-chip host: the rows run in ONE child
+    process whose ``XLA_FLAGS`` force 8 host devices before its backend
+    starts (this process already holds a one-device CPU runtime). On an
+    accelerator the rows run in-process over the real devices instead: a
+    child could not reach a chip its parent holds."""
+    import os
+    import subprocess
+    import sys
+
+    here = os.path.dirname(os.path.abspath(__file__))
+    root = os.path.dirname(here)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                        + env.get("XLA_FLAGS", "")).strip()
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(root, "src"), here]
+        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
+    code = ("import json, serving_bench; print('SCALING::' + json.dumps("
+            f"serving_bench._scaling_rows({quick})), flush=True)")
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=3600)
+    line = next((ln for ln in proc.stdout.splitlines()
+                 if ln.startswith("SCALING::")), None)
+    if proc.returncode != 0 or line is None:
+        raise RuntimeError(f"scaling rows failed:\n{proc.stdout}\n"
+                           f"{proc.stderr}")
+    print("\n".join(ln for ln in proc.stdout.splitlines()
+                    if ln.startswith("  mesh ")), flush=True)
+    return json.loads(line[len("SCALING::"):])
 
 
 def _scaling(quick: bool = True):
     """Goodput-vs-device-count curve for the mesh-sharded scheduler step.
 
-    Each row runs in its own subprocess so the 8-virtual-device XLA_FLAGS
-    can be set before jax import. The data-only chain (1,1)->(8,1) is the
-    scaling curve proper — lanes per replica held fixed, total slot lanes
-    grow with the data extent; (2,2) exercises the model-sharded output
-    layer inside the same serving step.
+    The data-only chain (1,1)->(8,1) is the scaling curve proper — lanes
+    per replica held fixed, total slot lanes grow with the data extent;
+    (2,2) exercises the model-sharded output layer inside the same serving
+    step. Rows run in this process over the real devices (shapes larger
+    than the device count are left out); on a CPU with fewer than 8
+    devices they run on 8 forced host devices in one child process.
 
     The GATED metric is ``tok_per_step`` on the virtual step clock (the
     same clock the overload trace uses): one compiled step must serve
@@ -479,37 +525,10 @@ def _scaling(quick: bool = True):
     cores the host has (possibly one), so wall clock measures core
     contention, not the per-replica-per-chip deployment this mesh maps to.
     """
-    import os
-    import subprocess
-    import sys
-
-    here = os.path.dirname(os.path.abspath(__file__))
-    root = os.path.dirname(here)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
-                        + env.get("XLA_FLAGS", "")).strip()
-    env["PYTHONPATH"] = os.pathsep.join(
-        [os.path.join(root, "src"), here]
-        + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
-    shapes = [(1, 1), (2, 1), (4, 1), (8, 1), (2, 2)]
-    rows = []
-    for d, m in shapes:
-        code = (f"import serving_bench; "
-                f"serving_bench._scaling_child({d}, {m}, {quick})")
-        proc = subprocess.run([sys.executable, "-c", code], env=env,
-                              capture_output=True, text=True, timeout=1800)
-        line = next((ln for ln in proc.stdout.splitlines()
-                     if ln.startswith("SCALING::")), None)
-        if proc.returncode != 0 or line is None:
-            raise RuntimeError(
-                f"scaling row data={d},model={m} failed:\n"
-                f"{proc.stdout}\n{proc.stderr}")
-        rows.append(json.loads(line[len("SCALING::"):]))
-        r = rows[-1]
-        print(f"  mesh data={d},model={m}: {r['tok_per_step']:.1f} "
-              f"tok/step ({r['goodput_tok_s']:.0f} tok/s wall), p95 "
-              f"{r['p95_token_ms']:.2f}ms, parity {r['token_parity']}, "
-              f"recompiles {r['recompiles_after_warmup']}", flush=True)
+    if jax.default_backend() == "cpu" and jax.device_count() < 8:
+        rows = _forced_cpu_rows(quick)
+    else:
+        rows = _scaling_rows(quick)
     chain = [r["tok_per_step"] for r in rows if r["model"] == 1]
     return {
         "lanes_per_replica": rows[0]["n_slots"],

@@ -21,21 +21,6 @@ from jax import lax
 NEG_INF = -1e30
 
 
-def shard_map(f, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    jax >= 0.5 exposes ``jax.shard_map`` (replication checking flag named
-    ``check_vma``); 0.4.x only has ``jax.experimental.shard_map.shard_map``
-    with the flag named ``check_rep``. All in-repo callers go through here.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma)
-
-
 def logspace_psum(x: jax.Array, axis_name: str) -> jax.Array:
     """psum of exp(x) carried in log domain, -inf-safe.
 

@@ -1,3 +1,11 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the output layer and the fused training loss.
+
+``on_tpu`` is the one place that picks a path from the platform: on a TPU
+the serving engine runs the compiled kernels; elsewhere it runs their XLA
+reference bodies, and a kernel called directly runs in interpret mode
+(how the CPU tests pin each kernel to its reference)."""
+import jax
+
+
+def on_tpu() -> bool:
+    return jax.default_backend() == "tpu"

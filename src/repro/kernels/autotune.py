@@ -13,8 +13,8 @@ repetitions (median of means), and persist the winner to a JSON cache keyed
 by ``(kernel, operand shapes, dtypes, backend, device kind)`` — the same
 key scheme as Triton/XLA autotuning caches, so a tuned serving binary never
 re-sweeps. Configs that fail to compile or run (e.g. a tile too large for
-VMEM) are skipped, not fatal.  Cache location: ``$REPRO_AUTOTUNE_CACHE``,
-else ``~/.cache/repro/autotune.json``.
+VMEM) are skipped; a sweep in which every config fails raises. Cache
+location: ``$REPRO_AUTOTUNE_CACHE``, else ``~/.cache/repro/autotune.json``.
 
 On CPU the Pallas kernels execute in interpret mode, where timings reflect
 the interpreter rather than the lowered kernel; sweeps still *work* (the
@@ -97,8 +97,9 @@ def autotune(kernel: str, candidates: List[Dict[str, int]],
 
     ``build(cfg)`` must return a zero-arg callable running the kernel with
     that config on the caller's operands. A candidate that raises during
-    compile/run is skipped; if every candidate fails, the first one is
-    returned so callers degrade to their defaults.
+    compile/run is skipped; if every candidate fails, RuntimeError names
+    each failure (a default handed back untested would only fail later,
+    inside the serving step).
     """
     path = cache_path(path)
     key = cache_key(kernel, args)
@@ -112,13 +113,16 @@ def autotune(kernel: str, candidates: List[Dict[str, int]],
         try:
             t = _time(build(cfg), reps)
         except Exception as e:                     # invalid tile/VMEM/etc.
-            results.append({"config": cfg, "error": f"{type(e).__name__}"})
+            results.append({"config": cfg,
+                            "error": f"{type(e).__name__}: {e}"[:500]})
             continue
         results.append({"config": cfg, "s": t})
         if t < best_t:
             best_cfg, best_t = cfg, t
     if best_cfg is None:
-        return dict(candidates[0])
+        raise RuntimeError(
+            f"autotune {kernel}: every candidate failed: "
+            + "; ".join(f"{r['config']} -> {r['error']}" for r in results))
     cache[key] = {"config": best_cfg, "s": best_t, "swept": results}
     _store(path, cache)
     return dict(best_cfg)

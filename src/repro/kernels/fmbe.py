@@ -34,6 +34,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_tpu
+
 
 def _phi_tile(x, om_ref, deg_ref, coef_ref, max_degree: int):
     """One (block_q, block_p) tile of phi. x (bq, d) f32; om (bp, M, d);
@@ -75,6 +77,19 @@ def _fmbe_z_kernel(x_ref, om_ref, deg_ref, coef_ref, lam_ref, out_ref,
         out_ref[...] = z_scr[...]
 
 
+def _compiler_params(block_q: int, block_p: int, max_degree: int, d: int,
+                     x_itemsize: int, lam_rows: int):
+    """Scoped-VMEM budget for one grid step. The omega tile alone is
+    block_p * max_degree * d f32 (10.5 MB at P-tile 128, M 8, d 2560) and
+    Pallas double-buffers every input, which overflows the 16 MiB default
+    scope at published widths; ask for what the tiles need plus headroom
+    for the (block_q, block_p) intermediates."""
+    tile_bytes = (block_p * max_degree * d * 4 + block_q * d * x_itemsize
+                  + (2 + lam_rows) * block_p * 4)
+    limit = max(2 * tile_bytes + 8 * 2 ** 20, 16 * 2 ** 20)
+    return pltpu.CompilerParams(vmem_limit_bytes=int(limit))
+
+
 def _pad_features(omega, degree, coef, block_p):
     """Pad the feature axis to a block multiple; pad features get coef == 0
     so they contribute exactly zero to phi and to z."""
@@ -93,7 +108,7 @@ def fmbe_phi(omega, degree, coef, x, *, block_q: int = 128,
     omega (P, max_degree, d), degree (P,), coef (P,), x (Q, d) -> (Q, P) f32.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     n_feat, max_degree, d = omega.shape
     q = x.shape[0]
     block_q = min(block_q, max(8, q))
@@ -113,6 +128,8 @@ def fmbe_phi(omega, degree, coef, x, *, block_q: int = 128,
         ],
         out_specs=pl.BlockSpec((block_q, block_p), lambda qi, pi: (qi, pi)),
         out_shape=jax.ShapeDtypeStruct((qp, pp), jnp.float32),
+        compiler_params=_compiler_params(block_q, block_p, max_degree, d,
+                                         xp.dtype.itemsize, block_q),
         interpret=interpret,
     )(xp, om, deg, cf)
     return out[:q, :n_feat]
@@ -131,7 +148,7 @@ def fmbe_z(omega, degree, coef, lam, x, *, block_q: int = 128,
     ``block_q``/``block_p`` are autotuned (kernels.autotune.tune_fmbe_z).
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     n_feat, max_degree, d = omega.shape
     q = x.shape[0]
     block_q = min(block_q, max(8, q))
@@ -163,6 +180,9 @@ def fmbe_z(omega, degree, coef, lam, x, *, block_q: int = 128,
         scratch_shapes=[
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
+        compiler_params=_compiler_params(block_q, block_p, max_degree, d,
+                                         xp.dtype.itemsize,
+                                         1 if lam.ndim == 1 else block_q),
         interpret=interpret,
     )(xp, om, deg, cf, lam_p)
     return out[:q, 0]

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import on_tpu
+
 NEG = -1e30
 
 
@@ -128,7 +130,7 @@ def _pad_to(x, m, axis):
 def fused_ce_fwd(h, w, labels, *, block_t=256, block_v=512, interpret=None):
     """Forward: (nll (T,), lse (T,)). h (T, d), w (V, d), labels (T,)."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     t, d = h.shape
     v = w.shape[0]
     block_t = min(block_t, max(8, t))
@@ -176,7 +178,7 @@ def fused_ce_bwd(h, w, labels, lse, g_nll, g_lse, *, block_t=256, block_v=512,
     accumulate-through-HBM revisit stride is >= 3 — see _bwd_kernel.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     t, d = h.shape
     v = w.shape[0]
     block_t = min(block_t, max(8, t))

@@ -43,7 +43,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_tpu
 from .topk_z import NEG, _select_topk
+
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
 
 
 # ---------------------------------------------------------------------------
@@ -67,7 +72,7 @@ def ivf_score(w_blocks, h, block_ids, *, interpret=None):
     path uses ``ivf_decode`` instead, which never materializes this tensor.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     nb, br, d = w_blocks.shape
     q, p = block_ids.shape
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -98,7 +103,7 @@ def _union_kernel(hid_ref, live_ref, h_ref, w_ref, out_ref):
     def _score():
         h = h_ref[...]                                      # (bq, d)
         w = w_ref[0]                                        # (br, d)
-        out_ref[:, 0, :] = jax.lax.dot_general(
+        out_ref[...] = jax.lax.dot_general(
             h, w, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
 
@@ -122,7 +127,7 @@ def union_scores(w_blocks, h, head_ids, head_live, *, block_q: int = 128,
     are zeros; callers mask through the plan's membership mask.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     nb, br, d = w_blocks.shape
     q = h.shape[0]
     u_cap = head_ids.shape[0]
@@ -138,17 +143,19 @@ def union_scores(w_blocks, h, head_ids, head_live, *, block_q: int = 128,
             pl.BlockSpec((1, br, d),
                          lambda qi, si, hid, lv: (hid[si], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((block_q, 1, br),
-                               lambda qi, si, hid, lv: (qi, si, 0)),
+        # slot si's scores are the si-th (block_q, br) tile of a lane-dense
+        # (Q, U_cap*br) slab (a (block_q, 1, br) block breaks TPU tiling)
+        out_specs=pl.BlockSpec((block_q, br),
+                               lambda qi, si, hid, lv: (qi, si)),
     )
     out = pl.pallas_call(
         _union_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((qp, u_cap, br), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((qp, u_cap * br), jnp.float32),
         interpret=interpret,
     )(head_ids.astype(jnp.int32),
       jnp.asarray(head_live, jnp.int32).reshape(1), hp, w_blocks)
-    return out[:q]
+    return out[:q].reshape(q, u_cap, br)
 
 
 # ---------------------------------------------------------------------------
@@ -181,8 +188,8 @@ def _decode_kernel(hid_ref, live_ref,                       # scalar prefetch
         scores = jax.lax.dot_general(
             h, w, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (bq, br)
-        scores = scores + logw_ref[...]                     # pad rows -> NEG
-        member = member_ref[...]                            # (bq, 1) 0/1
+        scores = scores + logw_ref[0]                       # pad rows -> NEG
+        member = member_ref[0]                              # (bq, 1) 0/1
         eff = jnp.where(member > 0, scores, NEG)
         m_prev = mh_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(eff, axis=1, keepdims=True))
@@ -206,7 +213,7 @@ def _decode_kernel(hid_ref, live_ref,                       # scalar prefetch
         s = jax.lax.dot_general(
             h, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (bq, tt)
-        acc = acc_ref[...]                                  # (bq, tt) 0/1
+        acc = acc_ref[0]                                    # (bq, tt) 0/1
         eff = jnp.where(acc > 0, s, NEG)
         m_prev = mt_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(eff, axis=1, keepdims=True))
@@ -254,26 +261,33 @@ def ivf_decode(w_blocks, h, head_ids, head_live, head_member, row_logw,
     Queries with zero accepted tail samples get tail_lse == -inf.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     nb, br, d = w_blocks.shape
     q = h.shape[0]
     n_head = head_ids.shape[0]
     l = tail_rows_g.shape[0]
     assert l >= 1, "fused decode needs at least one tail sample"
     block_q = min(block_q, max(8, q))
-    tail_tile = max(1, min(tail_tile, l))
+    tail_tile = _round_up(min(tail_tile, l), 8)
     pad_q = (-q) % block_q
     pad_l = (-l) % tail_tile
     hp = jnp.pad(h, ((0, pad_q), (0, 0)))
-    member_p = jnp.pad(head_member.astype(jnp.float32), ((0, pad_q), (0, 0)))
+    qp = hp.shape[0]
+    n_tiles = (l + pad_l) // tail_tile
+    # TPU blocks need their last two dims (8, 128)-aligned or whole, so the
+    # per-step operands that are one column of a query table are laid out
+    # with the step index leading: member (U, Qp, 1), accept
+    # (tiles, Qp, tail_tile), row_logw (nb, 1, br)
+    member_p = jnp.pad(head_member.astype(jnp.float32),
+                       ((0, pad_q), (0, 0))).T[:, :, None]
     # pad rows contribute via accept == 0 only — value never read; keep the
     # rows' own dtype (mixed-dtype dot with f32 accumulate, like the head
     # phase) so bf16 queries stay bit-comparable with the XLA reference
     wt_p = jnp.pad(tail_rows_g, ((0, pad_l), (0, 0)))
     accept_p = jnp.pad(tail_accept.astype(jnp.float32),
                        ((0, pad_q), (0, pad_l)))
-    qp = hp.shape[0]
-    n_tiles = (l + pad_l) // tail_tile
+    accept_p = accept_p.reshape(qp, n_tiles, tail_tile).transpose(1, 0, 2)
+    logw_p = row_logw.astype(jnp.float32)[:, None, :]
 
     def _ts(si):
         return jnp.clip(si - n_head, 0, n_tiles - 1)
@@ -288,17 +302,17 @@ def ivf_decode(w_blocks, h, head_ids, head_live, head_member, row_logw,
             pl.BlockSpec((1, br, d),
                          lambda qi, si, hid, lv:
                          (hid[jnp.minimum(si, lv[0] - 1)], 0, 0)),
-            pl.BlockSpec((1, br),
+            pl.BlockSpec((1, 1, br),
                          lambda qi, si, hid, lv:
-                         (hid[jnp.minimum(si, lv[0] - 1)], 0)),
-            pl.BlockSpec((block_q, 1),
+                         (hid[jnp.minimum(si, lv[0] - 1)], 0, 0)),
+            pl.BlockSpec((1, block_q, 1),
                          lambda qi, si, hid, lv:
-                         (qi, jnp.minimum(si, n_head - 1))),
+                         (jnp.minimum(si, n_head - 1), qi, 0)),
             # tail: dense (tail_tile, d) slab of the staged rows
             pl.BlockSpec((tail_tile, d),
                          lambda qi, si, hid, lv: (_ts(si), 0)),
-            pl.BlockSpec((block_q, tail_tile),
-                         lambda qi, si, hid, lv: (qi, _ts(si))),
+            pl.BlockSpec((1, block_q, tail_tile),
+                         lambda qi, si, hid, lv: (_ts(si), qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_q, 1), lambda qi, si, *_: (qi, 0)),
@@ -329,5 +343,5 @@ def ivf_decode(w_blocks, h, head_ids, head_live, head_member, row_logw,
         interpret=interpret,
     )(head_ids.astype(jnp.int32),
       jnp.asarray(head_live, jnp.int32).reshape(1),
-      hp, w_blocks, row_logw, member_p, wt_p, accept_p)
+      hp, w_blocks, logw_p, member_p, wt_p, accept_p)
     return hlse[:q, 0], tlse[:q, 0], topv[:q], topi[:q]

@@ -36,6 +36,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_tpu
+from .ivf_score import _round_up
 from .topk_z import NEG, _select_topk
 
 
@@ -113,7 +115,7 @@ def _probe_kernel(live_ref,                                 # scalar prefetch
         s = jax.lax.dot_general(
             h, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # (bq, tt)
-        acc = acc_ref[...]                                  # (bq, tt) 0/1
+        acc = acc_ref[0]                                    # (bq, tt) 0/1
         eff = jnp.where(acc > 0, s, NEG)
         m_prev = mt_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(eff, axis=1, keepdims=True))
@@ -163,7 +165,7 @@ def lsh_probe(w_cand, h, proj, cand_rows, cand_codes, cand_ok, cand_live,
     head_lse == log 0; zero accepted tail samples get tail_lse == -inf.
     """
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     c, d = w_cand.shape
     q = h.shape[0]
     ltab, kbits, _ = proj.shape
@@ -171,7 +173,7 @@ def lsh_probe(w_cand, h, proj, cand_rows, cand_codes, cand_ok, cand_live,
     assert l >= 1, "fused probe needs at least one tail sample"
     block_q = min(block_q, max(8, q))
     cand_tile = max(8, min(cand_tile, c))
-    tail_tile = max(1, min(tail_tile, l))
+    tail_tile = _round_up(min(tail_tile, l), 8)
     pad_q = (-q) % block_q
     pad_c = (-c) % cand_tile
     pad_l = (-l) % tail_tile
@@ -205,6 +207,9 @@ def lsh_probe(w_cand, h, proj, cand_rows, cand_codes, cand_ok, cand_live,
     cp = c + pad_c
     n_ctiles = cp // cand_tile
     n_ttiles = (l + pad_l) // tail_tile
+    # tile index leading, as in ivf_decode: a (block_q, tail_tile) block of
+    # a (Qp, l) table breaks TPU tiling unless tail_tile is 128-aligned
+    acc_p = acc_p.reshape(qp, n_ttiles, tail_tile).transpose(1, 0, 2)
 
     def _cs(si):
         return jnp.clip(si, 0, n_ctiles - 1)
@@ -226,8 +231,8 @@ def lsh_probe(w_cand, h, proj, cand_rows, cand_codes, cand_ok, cand_live,
             pl.BlockSpec((1, cand_tile), lambda qi, si, lv: (0, _cs(si))),
             # tail slabs
             pl.BlockSpec((tail_tile, ds), lambda qi, si, lv: (_ts(si), 0)),
-            pl.BlockSpec((block_q, tail_tile),
-                         lambda qi, si, lv: (qi, _ts(si))),
+            pl.BlockSpec((1, block_q, tail_tile),
+                         lambda qi, si, lv: (_ts(si), qi, 0)),
         ],
         out_specs=[
             pl.BlockSpec((block_q, 1), lambda qi, si, lv: (qi, 0)),

@@ -19,6 +19,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import on_tpu
+
 NEG = -1e30
 BIG = 2 ** 30  # python int — becomes an inline literal inside the kernel
 
@@ -82,7 +84,7 @@ def _topk_z_kernel(h_ref, w_ref, lse_ref, topv_ref, topi_ref,
 def topk_z(h, w, k: int, *, block_q=128, block_v=512, interpret=None):
     """h (Q, d), w (V, d) -> (lse (Q,), topv (Q, k), topi (Q, k))."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = not on_tpu()
     q, d = h.shape
     v = w.shape[0]
     block_q = min(block_q, max(8, q))

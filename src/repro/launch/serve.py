@@ -39,6 +39,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+from jax.sharding import PartitionSpec as P
 
 from ..configs import ServingConfig, get_config, reduced_config
 from ..core.backends import BACKENDS
@@ -46,6 +48,32 @@ from ..models import Model
 from ..obs import Observability, ObsConfig
 from ..serve import (Engine, Request, Scheduler, Server, generate,
                      poisson_arrivals)
+from .compile_cache import use_compile_cache
+
+
+def build_engine(cfg, seed: int, max_len: int, mesh=None,
+                 use_pallas=None) -> Engine:
+    """Random parameters from ``seed`` -> the serving ``Engine``.
+
+    The parameters are initialized inside one jit, so no float32 copy of a
+    weight ever materializes, and are placed where the engine serves them:
+    the default device, or replicated over ``mesh`` (the scheduler's mesh
+    step takes them replicated; building them on one device first would
+    hold two copies there). ``use_pallas`` as in ``Engine``: None lets the
+    platform choose."""
+    model = Model(cfg)
+    key = jax.random.PRNGKey(seed)
+    where = None if mesh is None else NamedSharding(mesh, P())
+    params = jax.jit(model.init, out_shardings=where)(key)
+    return Engine(model, params, max_len=max_len, key=key, mesh=mesh,
+                  use_pallas=use_pallas)
+
+
+def recompiles_after_warmup(sched: Scheduler) -> tuple:
+    """(step, admit) traces beyond the first of each: one step trace per
+    served estimator tier and one admit trace are the warmup."""
+    return (sched.step_traces - max(len(sched.traces_by_tier), 1),
+            sched.admit_traces - 1)
 
 
 def build_workload(n: int, vocab: int, gen: int, pmin: int, pmax: int,
@@ -82,7 +110,6 @@ def main():
                     help="sampled requests' temperature (every other "
                          "request decodes greedily)")
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--use-pallas", action="store_true")
     ap.add_argument("--mesh", default=None, metavar="data=K,model=M",
                     help="scale out over a (data, model) device mesh: slot "
                          "lanes split across K replicas, the output "
@@ -169,6 +196,7 @@ def main():
                     help="also run the one-request-at-a-time generate() "
                          "baseline over the same workload")
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     if args.method:
@@ -186,16 +214,15 @@ def main():
         mesh = make_serving_mesh(data=int(kv.get("data", 1)),
                                  model=int(kv.get("model", 1)))
 
-    model = Model(cfg)
     key = jax.random.PRNGKey(args.seed)
-    params = model.init(key)
     max_len = args.prompt_len_max + args.gen + 1
-    eng = Engine(model, params, max_len=max_len, key=key,
-                 use_pallas=args.use_pallas, mesh=mesh)
+    eng = build_engine(cfg, args.seed, max_len, mesh=mesh)
     mesh_note = "" if mesh is None else \
         f"  mesh data={mesh.shape['data']},model={mesh.shape['model']}"
+    path = "Pallas kernels" if eng.use_pallas else "XLA bodies"
     print(f"arch {cfg.name}  Z-method {cfg.partition.method}  "
-          f"vocab {cfg.vocab}  slots {args.slots}{mesh_note}")
+          f"vocab {cfg.vocab}  slots {args.slots}{mesh_note}  "
+          f"output layer: {path} on {jax.default_backend()}")
 
     if cfg.n_codebooks:
         # audio codebook heads have no slot-table path (multi-stream
@@ -264,9 +291,9 @@ def main():
         if args.metrics_snapshot:
             print(f"  snapshot: {args.metrics_snapshot}")
         obs.close()
-    step_extra = sched.step_traces - max(len(sched.traces_by_tier), 1)
+    step_extra, admit_extra = recompiles_after_warmup(sched)
     print(f"  recompiles after warmup would be: step={step_extra} "
-          f"admit={sched.admit_traces - 1} (0 expected; one trace per "
+          f"admit={admit_extra} (0 expected; one trace per "
           f"served tier: {dict(sched.traces_by_tier)})")
     if rep.dedup_by_fill:
         fills = ", ".join(f"{k}:{v:.2f}" for k, v in

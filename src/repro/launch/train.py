@@ -28,6 +28,7 @@ from ..train import (CheckpointManager, StragglerWatchdog,
                      make_index_refresh, make_instrumented_step,
                      make_train_step)
 from ..train.losses import ESTIMATOR_LOSSES, LOSSES
+from .compile_cache import use_compile_cache
 
 
 def main():
@@ -61,6 +62,7 @@ def main():
                          "at the end of the run")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = reduced_config(args.arch) if args.reduced else get_config(args.arch)
     model = Model(cfg)

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from ..configs.base import ModelConfig
 from ..core.backends import BACKENDS, BackendState, get_backend
 from ..core.decode import DecodeOut, apply_health_guard
+from ..kernels import on_tpu
 from ..models import Model
 
 
@@ -59,10 +60,17 @@ def _digest(v_blocks) -> tuple:
 class Engine:
     """Batched serving for one model. Retrieval state (IVF index, FMBE
     sketch) is built once from the output embedding at engine construction
-    ("index build time") by the method's registered backend."""
+    ("index build time") by the method's registered backend.
+
+    The platform picks the output layer's path (``kernels.on_tpu``): the
+    Pallas kernels on a TPU, their XLA reference bodies elsewhere. A mesh
+    engine always runs the XLA bodies under ``shard_map``.
+    ``use_pallas=True/False`` overrides the choice; the parity tests use it
+    to pin a kernel (interpreted on the CPU) to its reference."""
 
     def __init__(self, model: Model, params, max_len: int,
-                 key: Optional[jax.Array] = None, use_pallas: bool = False,
+                 key: Optional[jax.Array] = None,
+                 use_pallas: Optional[bool] = None,
                  autotune: bool = False, autotune_batch: int = 64,
                  device_index: bool = False, health_guard: bool = False,
                  mesh=None):
@@ -70,6 +78,8 @@ class Engine:
         self.cfg = model.cfg
         self.params = params
         self.max_len = max_len
+        if use_pallas is None:
+            use_pallas = mesh is None and on_tpu()
         self.use_pallas = use_pallas
         self.device_index = device_index
         self.health_guard = health_guard

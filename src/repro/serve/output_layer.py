@@ -33,7 +33,6 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..core import mince as _mince
-from ..core.distributed import shard_map
 from ..core.estimators import combine_head_tail_lse
 from ..core.feature_maps import FMBEState, fmbe_z_batch
 
@@ -282,8 +281,8 @@ def _shard_wrap(mesh, fn, ivf, h, key, batch_spec, n_out=3):
                                                   else ())
     out_specs = tuple(P(*batch_spec) for _ in range(n_out))
     args = (ivf, h) + ((key,) if key is not None else ())
-    return shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_vma=False)(*args)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 def sharded_ivf_decode(mesh, ivf: IVFSpecs, h: jax.Array, key: jax.Array,

@@ -139,14 +139,13 @@ class PrefixPool:
         self.load_traces = 0
         self.save_traces = 0
 
-        donate = (0,) if jax.default_backend() != "cpu" else ()
         load_kw = {} if cache_shardings is None else \
             {"out_shardings": cache_shardings}
         save_kw = {} if pool_sh is None else {"out_shardings": pool_sh}
         bt = block_tokens
         mcap = max_match_blocks
 
-        @partial(jax.jit, donate_argnums=donate, **load_kw)
+        @partial(jax.jit, donate_argnums=0, **load_kw)
         def load(cache, pool, ids, lane):
             self.load_traces += 1
 
@@ -158,7 +157,7 @@ class PrefixPool:
 
             return jax.tree.map(leaf_load, cache, pool)
 
-        @partial(jax.jit, donate_argnums=donate, **save_kw)
+        @partial(jax.jit, donate_argnums=0, **save_kw)
         def save(pool, cache, lane, start, block_id):
             self.save_traces += 1
 

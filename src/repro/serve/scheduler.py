@@ -73,7 +73,6 @@ from ..core.backends import (get_backend, shadow_exact_log_z,
 from ..core.decode import (HEALTH_EMPTY_HEAD, HEALTH_NONFINITE_SCORE,
                            HEALTH_NONFINITE_Z, apply_health_guard,
                            health_flags)
-from ..core.distributed import shard_map
 from ..obs.metrics import (TIER_IX, init_metric_state, observe_step,
                            shadow_rel_err)
 from ..obs.metrics import harvest as harvest_metric_state
@@ -406,10 +405,9 @@ class Scheduler:
         health_guard = self.health_guard
         max_len = eng.max_len
         est_key = jax.random.fold_in(self.key, 0xE57)
-        # donate the table: the step updates the KV cache in place instead
-        # of allocating + copying n_slots x max_len of it per token (CPU has
-        # no donation support and would warn on every compile, so gate it)
-        donate = (0,) if jax.default_backend() != "cpu" else ()
+        # the step donates the table (donate_argnums=0): the KV cache is
+        # updated in place instead of allocated + copied n_slots x max_len
+        # per token
 
         mesh = self.mesh
         tier_ix = TIER_IX[method]
@@ -552,7 +550,7 @@ class Scheduler:
             # trained checkpoint to a live server and the very next step
             # serves it from the same executable (shapes are identical
             # under device_index=True)
-            @partial(jax.jit, donate_argnums=donate)
+            @partial(jax.jit, donate_argnums=0)
             def step(table: SlotTable, params, bstate, fault_nan, fault_inf,
                      metrics, extras):
                 self.step_traces += 1   # python side effect: counts traces
@@ -581,12 +579,12 @@ class Scheduler:
                       "emitted": lane, "finished": lane, "overflow": lane,
                       "expired": lane, "health": lane,
                       "n_active": P(), "head_live": P()})
-        sharded = shard_map(body, mesh,
-                            in_specs=(table_specs, P(), bspecs, lane, lane,
-                                      P(), P()),
-                            out_specs=out_specs, check_vma=False)
+        sharded = jax.shard_map(body, mesh=mesh,
+                                in_specs=(table_specs, P(), bspecs, lane,
+                                          lane, P(), P()),
+                                out_specs=out_specs, check_vma=False)
 
-        @partial(jax.jit, donate_argnums=donate)
+        @partial(jax.jit, donate_argnums=0)
         def step(table: SlotTable, params, bstate, fault_nan, fault_inf,
                  metrics, extras):
             self.step_traces += 1
@@ -640,7 +638,6 @@ class Scheduler:
         prompt_cap = self.prompt_cap
         est_key = jax.random.fold_in(self.key, 0xE57)
         draft_key = jax.random.fold_in(self.key, 0xD4AF)
-        donate = (0,) if jax.default_backend() != "cpu" else ()
         mesh = self.mesh
         tier_ix = TIER_IX[method]
         n_slots = self.n_slots
@@ -815,7 +812,7 @@ class Scheduler:
             return new_table, new_metrics, outs
 
         if mesh is None:
-            @partial(jax.jit, donate_argnums=donate)
+            @partial(jax.jit, donate_argnums=0)
             def step(table: SlotTable, params, bstate, dstate, fault_nan,
                      fault_inf, metrics, extras):
                 self.step_traces += 1
@@ -841,12 +838,12 @@ class Scheduler:
                       "expired": lane, "health": lane, "accepted": lane,
                       "draft_flagged": lane,
                       "n_active": P(), "head_live": P()})
-        sharded = shard_map(body, mesh,
-                            in_specs=(table_specs, P(), bspecs, dspecs,
-                                      lane, lane, P(), P()),
-                            out_specs=out_specs, check_vma=False)
+        sharded = jax.shard_map(body, mesh=mesh,
+                                in_specs=(table_specs, P(), bspecs, dspecs,
+                                          lane, lane, P(), P()),
+                                out_specs=out_specs, check_vma=False)
 
-        @partial(jax.jit, donate_argnums=donate)
+        @partial(jax.jit, donate_argnums=0)
         def step(table: SlotTable, params, bstate, dstate, fault_nan,
                  fault_inf, metrics, extras):
             self.step_traces += 1
@@ -875,7 +872,6 @@ class Scheduler:
         self.tier = method
 
     def _build_admit(self):
-        donate = (0,) if jax.default_backend() != "cpu" else ()
         # under a mesh, pin the admitted table to the canonical shardings:
         # .at[slot].set on a 'data'-sharded lane would otherwise leave XLA
         # free to emit a differently-sharded (or replicated) result, and the
@@ -883,7 +879,7 @@ class Scheduler:
         jit_kw = {} if self.mesh is None else \
             {"out_shardings": self._table_sh}
 
-        @partial(jax.jit, donate_argnums=donate, **jit_kw)
+        @partial(jax.jit, donate_argnums=0, **jit_kw)
         def admit(table: SlotTable, slot, prompt_row, p_len, budget, key,
                   temp, sample_k, deadline, t0):
             self.admit_traces += 1

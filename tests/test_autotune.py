@@ -73,9 +73,11 @@ class TestAutotuneCore:
                 raise RuntimeError("no")
             return run
 
-        best = at.autotune("dead", [{"x": 5}, {"x": 6}], build,
-                           (jnp.zeros(()),), reps=1, path=path)
-        assert best == {"x": 5}
+        # no candidate compiles: the sweep raises (naming every failure)
+        # rather than hand back an untested default that fails again later
+        with pytest.raises(RuntimeError, match="every candidate failed"):
+            at.autotune("dead", [{"x": 5}, {"x": 6}], build,
+                        (jnp.zeros(()),), reps=1, path=path)
         assert not os.path.exists(path)    # nothing worth caching
 
 

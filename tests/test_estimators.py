@@ -3,10 +3,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-try:
-    from hypothesis import given, settings, strategies as st
-except ModuleNotFoundError:          # offline container: deterministic shim
-    from _hyp_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import (exact_log_z, mimps_log_z, uniform_log_z, nmimps_log_z,
                         mince_log_z, head_tail_log_z, combine_head_tail_lse,

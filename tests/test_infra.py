@@ -224,7 +224,7 @@ import jax, jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.distributed import (sharded_exact_log_z, sharded_top_k,
-                                    sharded_mimps_log_z, shard_map)
+                                    sharded_mimps_log_z)
 
 mesh = jax.make_mesh((8,), ("model",))
 N, D = 4096, 32
@@ -234,7 +234,7 @@ q = v[7]
 
 @jax.jit
 def dist_lse(v, q):
-    return shard_map(
+    return jax.shard_map(
         lambda vl, q: sharded_exact_log_z(vl, q),
         mesh=mesh, in_specs=(P("model", None), P()), out_specs=P())(v, q)
 
@@ -244,7 +244,7 @@ assert abs(float(lz - ref)) < 1e-3, (lz, ref)
 
 @jax.jit
 def dist_topk(v, q):
-    return shard_map(
+    return jax.shard_map(
         lambda vl, q: sharded_top_k(vl, q, 8),
         mesh=mesh, in_specs=(P("model", None), P()), out_specs=P(),
         check_vma=False)(v, q)
@@ -256,7 +256,7 @@ np.testing.assert_array_equal(np.asarray(tk.ids), np.asarray(ref_i))
 
 @jax.jit
 def dist_mimps(v, q, key):
-    return shard_map(
+    return jax.shard_map(
         lambda vl, q, k: sharded_mimps_log_z(vl, q, 64, 64, k)[0],
         mesh=mesh, in_specs=(P("model", None), P(), P()),
         out_specs=P(), check_vma=False)(v, q, key)

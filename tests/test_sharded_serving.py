@@ -195,7 +195,6 @@ import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 from repro.configs.base import PartitionConfig
 from repro.core import backends as B
-from repro.core.distributed import shard_map
 from repro.launch.mesh import make_serving_mesh
 
 cfg = PartitionConfig(block_rows=32, n_probe=4, l=64, n_clusters=16,
@@ -216,8 +215,9 @@ for (dp, mp) in [(1, 4), (2, 4)]:
         specs = B.state_partition_specs(st, mp)
         body = lambda s, hh: bk.shard_decode(s, hh, kd, cfg, k=4,
                                              active=active)
-        out = jax.jit(shard_map(body, mesh, in_specs=(specs, P()),
-                                out_specs=P(), check_vma=False))(st, h)
+        out = jax.jit(jax.shard_map(body, mesh=mesh,
+                                    in_specs=(specs, P()), out_specs=P(),
+                                    check_vma=False))(st, h)
         if method in ("exact", "selfnorm"):
             # candidates exact; log_z only to psum reduction-order rounding
             assert bool(jnp.all(ref.top_score == out.top_score)), method

@@ -7,10 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:                                      # pragma: no cover
-    from _hyp_fallback import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.configs import reduced_config
 from repro.configs.base import TrainConfig
