@@ -56,7 +56,7 @@ def summarize(events) -> dict:
     by_phase = Counter(e["ph"] for e in events)
     span_ms = defaultdict(float)
     span_n = Counter()
-    step_tiers = Counter()      # device_step:<tier> -> count
+    step_tiers = Counter()      # tier -> scheduler steps served at it
     outcomes = Counter()
     transitions = []
     shed = 0
@@ -66,8 +66,8 @@ def summarize(events) -> dict:
         if ph == "X":
             span_n[name] += 1
             span_ms[name] += e.get("dur", 0) / 1e3
-            if name.startswith("device_step:"):
-                step_tiers[name.split(":", 1)[1]] += 1
+            if name == "sched.step":
+                step_tiers[e.get("args", {}).get("tier", "?")] += 1
             elif name == "request":
                 outcomes[e.get("args", {}).get("outcome", "ok")] += 1
         elif ph == "i":
@@ -88,9 +88,8 @@ def reconcile(summary: dict, snapshot: dict):
     """Check the snapshot's tokens_by_tier against tiers seen in the trace.
 
     Every tier that emitted tokens per the harvested device counters must
-    have at least one ``device_step:<tier>`` span in the trace (and vice
-    versa for tiers that stepped enough to harvest). Returns a list of
-    mismatch strings (empty = reconciled).
+    have at least one ``sched.step`` span of that tier in the trace.
+    Returns a list of mismatch strings (empty = reconciled).
     """
     problems = []
     harvest = snapshot.get("harvest", {})
@@ -101,7 +100,7 @@ def reconcile(summary: dict, snapshot: dict):
         if t not in traced:
             problems.append(
                 f"tier {t!r} emitted {tok_by_tier[t]} tokens per snapshot "
-                f"but has no device_step span in the trace")
+                f"but has no step span in the trace")
     snap_total = harvest.get("tokens_total")
     if snap_total is not None and tok_by_tier:
         s = sum(tok_by_tier.values())

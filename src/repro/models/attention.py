@@ -154,8 +154,9 @@ def decode_self_attention(p: Params, x: jax.Array, cache: KVCache, pos,
     k = apply_rope(k, posv, cfg.rope_theta)
     s_max = cache.k.shape[1]
     slot = (pos % window) if window else pos
-    new_k = _dyn_update(cache.k, k, slot)
-    new_v = _dyn_update(cache.v, v, slot)
+    with jax.named_scope("kv_cache"):
+        new_k = _dyn_update(cache.k, k, slot)
+        new_v = _dyn_update(cache.v, v, slot)
     valid = jnp.minimum(pos + 1, s_max)
     scale = cfg.resolved_head_dim ** -0.5
     s = jnp.einsum("bqkgd,bskd->bkgqs", q, new_k,

@@ -69,28 +69,32 @@ def init_tblock(key, cfg: ModelConfig, kind: str = "dense",
 
 
 def tblock_fwd(p: Params, x, cfg, *, kind="dense", window=0):
-    h = self_attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps), cfg,
-                       window=window)
+    with jax.named_scope("attention"):
+        h = self_attention(p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps),
+                           cfg, window=window)
     x = x + h
-    y = rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if kind == "moe":
-        f, aux = moe_block(p["ffn"], y, cfg)
-    else:
-        f, aux = mlp(p["ffn"], y, cfg.act), ZERO_AUX
+    with jax.named_scope("mlp"):
+        y = rmsnorm(p["ln2"], x, cfg.norm_eps)
+        if kind == "moe":
+            f, aux = moe_block(p["ffn"], y, cfg)
+        else:
+            f, aux = mlp(p["ffn"], y, cfg.act), ZERO_AUX
     return x + f, aux
 
 
 def tblock_decode(p: Params, x, cache: KVCache, pos, cfg, *, kind="dense",
                   window=0):
-    h, cache = decode_self_attention(
-        p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps), cache, pos, cfg,
-        window=window)
+    with jax.named_scope("attention"):
+        h, cache = decode_self_attention(
+            p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps), cache, pos, cfg,
+            window=window)
     x = x + h
-    y = rmsnorm(p["ln2"], x, cfg.norm_eps)
-    if kind == "moe":
-        f, _ = moe_block(p["ffn"], y, cfg)
-    else:
-        f = mlp(p["ffn"], y, cfg.act)
+    with jax.named_scope("mlp"):
+        y = rmsnorm(p["ln2"], x, cfg.norm_eps)
+        if kind == "moe":
+            f, _ = moe_block(p["ffn"], y, cfg)
+        else:
+            f = mlp(p["ffn"], y, cfg.act)
     return x + f, cache
 
 
@@ -227,6 +231,7 @@ class Model:
 
     # -- full-sequence forward (train / prefill) -----------------------------
 
+    @jax.named_scope("trunk")
     def forward(self, p: Params, tokens, *, img=None) -> Tuple[Any, Dict]:
         """tokens (B, S[, C]) -> (hidden (B, S, d), aux)."""
         cfg = self.cfg
@@ -372,6 +377,7 @@ class Model:
             return st
         raise ValueError(fam)
 
+    @jax.named_scope("trunk")
     def decode_step(self, p: Params, state, token, pos, *, img=None):
         """token (B,) or (B, C) -> (hidden_last (B, d), new_state)."""
         cfg = self.cfg

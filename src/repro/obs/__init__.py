@@ -5,9 +5,11 @@ Three layers, composable and individually optional:
  * **Device-resident metrics** (``obs.metrics``): the scheduler threads a
    ``MetricState`` pytree through its one compiled step; the host harvests
    it on ``ObsConfig.harvest_every`` cadence into the registry.
- * **Per-request tracing** (``obs.tracing``): lifecycle spans (enqueue ->
-   admit -> replay -> decode -> complete/shed/evict), per-step device/host
-   phases and harvested counter tracks as Chrome-trace JSONL.
+ * **Tracing** (``obs.tracing``): the scheduler records its step phases
+   and admissions in a span log as it runs; ``Observability`` writes them,
+   with the request lifecycle spans (enqueue -> admit -> replay -> decode
+   -> complete/shed/evict) and harvested counter tracks, as Chrome-trace
+   JSONL.
  * **Estimator-quality telemetry**: shadow-sampled exact log-Z inside the
    compiled step (``core.backends.shadow_exact_log_z`` under ``lax.cond``)
    surfaces a live per-tier rel-err stream; exposition via the Prometheus
@@ -27,13 +29,15 @@ from typing import Optional
 
 from ..configs.base import ObsConfig
 from .metrics import (LATENCY_EDGES_MS, OCC_EDGES, QUEUE_EDGES, TIER_IX,
-                      TIERS, MetricState, harvest, hist_quantile,
-                      init_metric_state, observe_step, shadow_rel_err)
+                      TIERS, MetricState, StepHistograms, harvest,
+                      hist_quantile, init_metric_state, observe_step,
+                      shadow_rel_err)
 from .registry import MetricsRegistry
-from .tracing import TraceWriter
+from .tracing import SPANS, Span, SpanLog, SpanRecord, TraceWriter
 
 __all__ = ["Observability", "ObsConfig", "MetricsRegistry", "TraceWriter",
-           "MetricState", "TIERS", "TIER_IX", "LATENCY_EDGES_MS",
+           "SpanLog", "SpanRecord", "Span", "SPANS", "MetricState",
+           "StepHistograms", "TIERS", "TIER_IX", "LATENCY_EDGES_MS",
            "QUEUE_EDGES", "OCC_EDGES", "init_metric_state", "observe_step",
            "harvest", "hist_quantile", "shadow_rel_err"]
 
@@ -61,6 +65,8 @@ class Observability:
         self._harvests = 0
         self._tiers_seen = 0
         self._submit_at: dict = {}     # req_id -> wall stamp at enqueue
+        self._span_mark = 0            # the scheduler's span log, read up
+                                       # to here
 
     # -- wiring ---------------------------------------------------------------
 
@@ -70,6 +76,8 @@ class Observability:
         events; everything else flows through the server's obs calls."""
         server.scheduler.shadow_every = self.cfg.shadow_every
         server.scheduler.engine.obs = self
+        if server.scheduler.spans is not None:
+            self._span_mark = server.scheduler.spans.added
         if self.tracer:
             self.tracer.instant("observability_attached", args={
                 "tiers": list(TIERS),
@@ -106,18 +114,16 @@ class Observability:
     def on_step(self, server, rec: dict) -> None:
         self._steps += 1
         if self.tracer:
-            t0, td, te, tn = (rec.get("t_start"), rec.get("t_dispatch"),
-                              rec.get("t_device_done"), rec.get("t_done"))
-            if td is not None and te is not None:
-                self.tracer.span(f"device_step:{rec['tier']}", td, te,
-                                 args={"n_active": rec["n_active"],
-                                       "n_emitted": rec["n_emitted"],
-                                       "spec_accepted":
-                                           rec.get("spec_accepted", 0)})
-            if t0 is not None and tn is not None:
-                self.tracer.span("host_step", te or t0, tn,
-                                 args={"completions":
-                                       len(rec["completions"])})
+            spans = server.scheduler.spans
+            if spans is not None:
+                # this step's phases and the admissions since the last
+                # step, as the scheduler recorded them
+                recs, self._span_mark = spans.since(self._span_mark)
+                self.tracer.write_spans(recs, {"sched.step": {
+                    "tier": rec["tier"], "n_active": rec["n_active"],
+                    "n_emitted": rec["n_emitted"],
+                    "spec_accepted": rec.get("spec_accepted", 0),
+                    "completions": len(rec["completions"])}})
             for comp in rec["completions"]:
                 self._trace_completion(comp)
             # tier transitions appended by the server since last look
@@ -182,8 +188,7 @@ class Observability:
                                 args={"reason": outcome})
 
     def _harvest(self, server, force_snapshot: bool = False) -> None:
-        sched = server.scheduler
-        h = harvest(sched.metrics_state, sched.n_slots)
+        h = server.scheduler.harvest_metrics()
         self.last_harvest = h
         self._harvests += 1
         self._push_registry(h, server)

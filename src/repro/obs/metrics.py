@@ -18,13 +18,13 @@ Harvesting is a cadence-controlled ``jax.device_get`` of the whole pytree —
 the only device->host traffic observability adds (the per-step ``outs``
 readback already exists for token streaming and stays untouched).
 
-The step-latency histogram is fed forward: the host measures step N's
-device phase and passes it into step N+1 as traced data (``last_ms`` /
-``last_tier``), so the buckets live on device with everything else and no
-extra sync point appears. ``last_ms < 0`` (the first step) records nothing.
+The queue-depth and step-latency histograms are host values, so they stay
+on the host (``StepHistograms``): the scheduler fills them at the end of
+each step from the step's own spans, and uploads nothing for them.
 """
 from __future__ import annotations
 
+import bisect
 import dataclasses
 
 import jax
@@ -41,7 +41,7 @@ from ..core.decode import (HEALTH_EMPTY_HEAD, HEALTH_NONFINITE_SCORE,
 TIERS: tuple = tuple(sorted(BACKENDS))
 TIER_IX: dict = {t: i for i, t in enumerate(TIERS)}
 
-# bucket UPPER edges, shared by device accumulation, harvest, the serving
+# bucket UPPER edges, shared by accumulation, harvest, the serving
 # benchmark rows and obs_report: value v lands in the first bucket whose
 # edge exceeds it; the trailing bucket is the +inf overflow
 LATENCY_EDGES_MS: tuple = (0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0,
@@ -65,10 +65,7 @@ class MetricState:
     tokens_by_tier: jax.Array   # (T,) emitted tokens per estimator tier
     active_sum: jax.Array       # ()   sum of live lanes per step (gauge avg)
     fill_sum: jax.Array         # ()   sum of probe-union live blocks
-    queue_sum: jax.Array        # ()   sum of admission-queue depth per step
-    queue_hist: jax.Array       # (NQ,) queue-depth histogram
     occ_hist: jax.Array         # (NO,) occupancy-fraction histogram
-    latency_hist: jax.Array     # (T, NL) device-step-ms histogram per tier
     health_flagged: jax.Array   # ()   lane-steps health-guard flagged
     health_by_cause: jax.Array  # (3,) [nonfinite_z, empty_head,
                                 #       nonfinite_score] lane-steps
@@ -85,8 +82,7 @@ def init_metric_state() -> MetricState:
     zf = lambda *s: jnp.zeros(s, jnp.float32)
     return MetricState(
         steps=z(), tokens_total=z(), tokens_by_tier=z(_NT),
-        active_sum=z(), fill_sum=z(), queue_sum=z(),
-        queue_hist=z(_NQ), occ_hist=z(_NO), latency_hist=z(_NT, _NL),
+        active_sum=z(), fill_sum=z(), occ_hist=z(_NO),
         health_flagged=z(), health_by_cause=z(3),
         spec_proposed=z(), spec_accepted=z(), draft_flagged=z(),
         shadow_count=z(_NT), shadow_err_sum=zf(_NT), shadow_err_max=zf(_NT))
@@ -98,8 +94,7 @@ def _bucket(edges: tuple, v) -> jax.Array:
 
 
 def observe_step(m: MetricState, tier_ix: int, n_slots: int, *,
-                 n_active, head_live, n_emitted, health_flags,
-                 queue_depth, last_ms, last_tier, shadow=None,
+                 n_active, head_live, n_emitted, health_flags, shadow=None,
                  spec_proposed=None, spec_accepted=None, draft_flagged=None,
                  axis_name=None) -> MetricState:
     """Accumulate one step into the metric state (traced; runs inside the
@@ -109,8 +104,7 @@ def observe_step(m: MetricState, tier_ix: int, n_slots: int, *,
     them for its own outs); ``n_emitted``, ``health_flags`` (per local
     lane), the spec scalars and the ``shadow`` triple are this replica's
     local contributions and get psum-reduced here when ``axis_name`` is
-    set. ``queue_depth`` / ``last_ms`` / ``last_tier`` are replicated host
-    scalars.
+    set.
     """
     i32 = jnp.int32
     hf = jnp.asarray(health_flags)
@@ -138,10 +132,7 @@ def observe_step(m: MetricState, tier_ix: int, n_slots: int, *,
         sh_n = jax.lax.psum(sh_n, axis_name)
         sh_max = jax.lax.pmax(sh_max, axis_name)
     n_active = jnp.asarray(n_active, i32)
-    lat_ok = (jnp.asarray(last_ms, jnp.float32) >= 0.0).astype(i32)
-    lat_b = _bucket(LATENCY_EDGES_MS, last_ms)
     occ_b = _bucket(OCC_EDGES, n_active.astype(jnp.float32) / n_slots)
-    q_b = _bucket(QUEUE_EDGES, queue_depth)
     return dataclasses.replace(
         m,
         steps=m.steps + 1,
@@ -149,11 +140,7 @@ def observe_step(m: MetricState, tier_ix: int, n_slots: int, *,
         tokens_by_tier=m.tokens_by_tier.at[tier_ix].add(n_emitted),
         active_sum=m.active_sum + n_active,
         fill_sum=m.fill_sum + jnp.asarray(head_live, i32),
-        queue_sum=m.queue_sum + jnp.asarray(queue_depth, i32),
-        queue_hist=m.queue_hist.at[q_b].add(1),
         occ_hist=m.occ_hist.at[occ_b].add(1),
-        latency_hist=m.latency_hist.at[jnp.asarray(last_tier, i32),
-                                       lat_b].add(lat_ok),
         health_flagged=m.health_flagged + flagged,
         health_by_cause=m.health_by_cause + causes,
         spec_proposed=m.spec_proposed + sp,
@@ -184,11 +171,41 @@ def shadow_rel_err(log_z, ref_log_z, active) -> tuple:
             ok.sum().astype(jnp.int32))
 
 
+class StepHistograms:
+    """Queue depth and step latency of every step, per tier for the
+    latency, kept on the host. Cumulative, like the device counters."""
+
+    def __init__(self):
+        self.steps = 0
+        self.queue_sum = 0
+        self.queue_hist = [0] * _NQ
+        self.latency_hist = [[0] * _NL for _ in TIERS]
+
+    def observe(self, queue_depth: int, step_ms: float, tier: str) -> None:
+        self.steps += 1
+        self.queue_sum += queue_depth
+        self.queue_hist[bisect.bisect_left(QUEUE_EDGES, queue_depth)] += 1
+        self.latency_hist[TIER_IX[tier]][
+            bisect.bisect_left(LATENCY_EDGES_MS, step_ms)] += 1
+
+    def harvest(self) -> dict:
+        return {
+            "queue_depth_mean": self.queue_sum / max(self.steps, 1),
+            "queue_hist": list(self.queue_hist),
+            "queue_edges": list(QUEUE_EDGES),
+            "latency_hist_by_tier": {t: list(self.latency_hist[i])
+                                     for t, i in TIER_IX.items()
+                                     if sum(self.latency_hist[i])},
+            "latency_edges_ms": list(LATENCY_EDGES_MS),
+        }
+
+
 def harvest(m: MetricState, n_slots: int) -> dict:
     """ONE device->host read of the whole metric pytree, flattened into a
     plain dict (python scalars + per-tier sub-dicts) for the registry,
     snapshots and the serving benchmark. Non-destructive: counters stay
-    cumulative on device."""
+    cumulative on device. The host-side ``StepHistograms`` add their own
+    keys (``Scheduler.harvest_metrics``)."""
     g = jax.device_get(m)
     steps = int(g.steps)
     tiers_tok = {t: int(g.tokens_by_tier[i]) for t, i in TIER_IX.items()
@@ -200,21 +217,14 @@ def harvest(m: MetricState, n_slots: int) -> dict:
             shadow[t] = {"count": n,
                          "rel_err_mean": float(g.shadow_err_sum[i]) / n,
                          "rel_err_max": float(g.shadow_err_max[i])}
-    lat = {t: [int(c) for c in g.latency_hist[i]]
-           for t, i in TIER_IX.items() if int(g.latency_hist[i].sum())}
     return {
         "steps": steps,
         "tokens_total": int(g.tokens_total),
         "tokens_by_tier": tiers_tok,
         "occupancy_mean": float(g.active_sum) / (max(steps, 1) * n_slots),
         "fill_mean": float(g.fill_sum) / max(steps, 1),
-        "queue_depth_mean": float(g.queue_sum) / max(steps, 1),
-        "queue_hist": [int(c) for c in g.queue_hist],
-        "queue_edges": list(QUEUE_EDGES),
         "occ_hist": [int(c) for c in g.occ_hist],
         "occ_edges": list(OCC_EDGES),
-        "latency_hist_by_tier": lat,
-        "latency_edges_ms": list(LATENCY_EDGES_MS),
         "health_flagged": int(g.health_flagged),
         "health_by_cause": {
             "nonfinite_z": int(g.health_by_cause[0]),

@@ -73,8 +73,9 @@ from ..core.backends import (get_backend, shadow_exact_log_z,
 from ..core.decode import (HEALTH_EMPTY_HEAD, HEALTH_NONFINITE_SCORE,
                            HEALTH_NONFINITE_Z, apply_health_guard,
                            health_flags)
-from ..obs.metrics import (TIER_IX, init_metric_state, observe_step,
-                           shadow_rel_err)
+from ..obs import tracing
+from ..obs.metrics import (TIER_IX, StepHistograms, init_metric_state,
+                           observe_step, shadow_rel_err)
 from ..obs.metrics import harvest as harvest_metric_state
 from .prefix_cache import PrefixPool, cache_is_kv_only
 
@@ -229,7 +230,8 @@ class Scheduler:
                  health_guard: bool = True,
                  spec_draft: Optional[str] = None, spec_k: int = 1,
                  spec_draft_probes: int = 0, prefix_cache_blocks: int = 0,
-                 prefix_block_tokens: int = 8):
+                 prefix_block_tokens: int = 8,
+                 spans: Optional[tracing.SpanLog] = tracing.SPANS):
         if engine.cfg.n_codebooks:
             raise NotImplementedError(
                 "the slot scheduler serves single-stream text heads; "
@@ -269,6 +271,9 @@ class Scheduler:
         self._slot_req: List[Optional[Request]] = [None] * n_slots
         self._slot_acc: List[Optional[Completion]] = [None] * n_slots
         self._no_fault = jnp.zeros((n_slots,), bool)
+        # the step's spans (obs.tracing): the process's log unless given
+        # another; None records nothing
+        self.spans = spans
         # -- observability (obs/, DESIGN.md SS17): the metric pytree is
         # ALWAYS threaded through the compiled step — enabling harvesting
         # or shadow sampling later changes only traced data, never the
@@ -277,10 +282,11 @@ class Scheduler:
                                            # (0 = off); obs.Observability
                                            # sets it from ObsConfig
         self.metrics_state = init_metric_state()
-        self._last_step_ms = -1.0          # previous step's device phase,
-                                           # fed forward into the device
-                                           # latency histogram (< 0: none)
-        self._last_step_tier = engine.backend.method
+        self.step_hist = StepHistograms()  # queue depth and step latency,
+                                           # host values kept on the host
+        # the shadow cadence flag, placed once: a step passes one of these
+        # two and uploads nothing
+        self._shadow_flags = (jnp.asarray(False), jnp.asarray(True))
         self.table = self._init_table()
         if self.mesh is not None:
             # canonical shardings: jit keys its compile cache on INPUT
@@ -298,6 +304,8 @@ class Scheduler:
             # same psum-reduced globals); pin them so the step executable's
             # input-sharding cache key never drifts
             self.metrics_state = jax.device_put(self.metrics_state,
+                                                self._repl_sh)
+            self._shadow_flags = jax.device_put(self._shadow_flags,
                                                 self._repl_sh)
         # -- estimator-speculative decoding (DESIGN.md SS16b): a cheap
         # registry backend drafts spec_k tokens per lane inside the step;
@@ -444,15 +452,17 @@ class Scheduler:
             # -- ONE shared estimator decode across every lane; masked lanes
             #    stay out of the probe union
             k_est = jax.random.fold_in(est_key, table.step_idx)
-            if mesh is None:
-                out = backend.decode(bstate, h, k_est, pc, k=pc.sample_k,
-                                     use_pallas=use_pallas,
-                                     active=table.active, **kernel_cfg)
-            else:
-                out = backend.shard_decode(bstate, h, k_est, pc,
-                                           k=pc.sample_k,
-                                           active=table.active,
-                                           axis_name="model")
+            with jax.named_scope("output_layer"):
+                if mesh is None:
+                    out = backend.decode(bstate, h, k_est, pc,
+                                         k=pc.sample_k,
+                                         use_pallas=use_pallas,
+                                         active=table.active, **kernel_cfg)
+                else:
+                    out = backend.shard_decode(bstate, h, k_est, pc,
+                                               k=pc.sample_k,
+                                               active=table.active,
+                                               axis_name="model")
             # -- lane-scoped fault injection: the masks are traced arguments
             #    (all-False arrays in normal service — same executable), and
             #    every downstream consumer is per-lane, so a corrupted lane's
@@ -533,9 +543,7 @@ class Scheduler:
                 metrics, tier_ix, n_slots,
                 n_active=n_active, head_live=head_live,
                 n_emitted=emitted.astype(jnp.int32).sum(),
-                health_flags=flags, queue_depth=extras["queue_depth"],
-                last_ms=extras["last_ms"], last_tier=extras["last_tier"],
-                shadow=shadow,
+                health_flags=flags, shadow=shadow,
                 axis_name=None if mesh is None else "data")
             outs = {"token": tok, "log_prob": score - out.log_z,
                     "log_z": out.log_z, "emitted": emitted,
@@ -571,8 +579,8 @@ class Scheduler:
         bspecs = state_partition_specs(bstate, self.mesh.shape["model"])
         self._bstate_sh[method] = self._shardings_of(bspecs)
         lane = P("data")
-        # metric state + host scalars ride replicated (P() prefix covers the
-        # whole pytree): every replica accumulates identical psum-reduced
+        # metric state + the shadow flag ride replicated (P() prefix covers
+        # the whole pytree): every replica accumulates identical psum-reduced
         # counters, so the host may harvest any one shard
         out_specs = (table_specs, P(),
                      {"token": lane, "log_prob": lane, "log_z": lane,
@@ -704,11 +712,12 @@ class Scheduler:
             #    the key only drives tail sampling, i.e. log Ẑ)
             hseq = jnp.stack(hs, 1)
             k_est = jax.random.fold_in(est_key, table.step_idx)
-            out = verify_decode(backend, bstate, hseq, k_est, pc,
-                                k=pc.sample_k, active=act,
-                                use_pallas=use_pallas,
-                                axis_name=None if mesh is None else "model",
-                                **kernel_cfg)
+            with jax.named_scope("output_layer"):
+                out = verify_decode(backend, bstate, hseq, k_est, pc,
+                                    k=pc.sample_k, active=act,
+                                    use_pallas=use_pallas,
+                                    axis_name=None if mesh is None
+                                    else "model", **kernel_cfg)
             corrupt_r = jnp.repeat(corrupt, kk)
             bad_r = jnp.repeat(bad_val, kk)
             out = out._replace(
@@ -796,9 +805,7 @@ class Scheduler:
                 metrics, tier_ix, n_slots,
                 n_active=n_active, head_live=head_live,
                 n_emitted=e.sum(),
-                health_flags=flags_l, queue_depth=extras["queue_depth"],
-                last_ms=extras["last_ms"], last_tier=extras["last_tier"],
-                shadow=shadow,
+                health_flags=flags_l, shadow=shadow,
                 spec_proposed=act.astype(jnp.int32).sum() * kk,
                 spec_accepted=a.sum(),
                 draft_flagged=(draft_bad & act).astype(jnp.int32).sum(),
@@ -971,7 +978,12 @@ class Scheduler:
         admission is the last point where a python error is possible).
         ``deadline_steps`` is the lane's eviction countdown in scheduler
         steps (None = no deadline); the server passes the request's
-        *remaining* deadline so queue wait counts against it."""
+        *remaining* deadline so queue wait counts against it. Recorded as
+        one ``sched.admit`` span carrying the request's id."""
+        with tracing.Span(self.spans, "sched.admit", request.req_id):
+            return self._admit(request, deadline_steps)
+
+    def _admit(self, request: Request, deadline_steps: Optional[int]) -> int:
         if self.injector is not None:
             # fault hook BEFORE any state mutates: a rejected admission
             # leaves the scheduler exactly as it was
@@ -1013,12 +1025,14 @@ class Scheduler:
         sk = request.sample_k or self.engine.cfg.partition.sample_k
         sk = max(1, min(sk, self.engine.cfg.partition.sample_k))
         ddl = NO_DEADLINE if deadline_steps is None else int(deadline_steps)
+        # numpy arguments ride along with the call: a jnp scalar would
+        # dispatch a convert_element_type program of its own
         self.table = self._admit_fn(
-            self.table, jnp.int32(slot), jnp.asarray(prompt_row),
-            jnp.int32(p_len), jnp.int32(request.max_new_tokens),
-            jnp.asarray(request.key, jnp.uint32), jnp.float32(
-                request.temperature), jnp.int32(sk), jnp.int32(ddl),
-            jnp.int32(t0))
+            self.table, np.int32(slot), prompt_row, np.int32(p_len),
+            np.int32(request.max_new_tokens),
+            jnp.asarray(request.key, jnp.uint32),
+            np.float32(request.temperature), np.int32(sk), np.int32(ddl),
+            np.int32(t0))
         self._slot_req[slot] = request
         self._slot_acc[slot] = Completion(
             request=request, tokens=[], log_probs=[], log_zs=[],
@@ -1032,19 +1046,53 @@ class Scheduler:
         (``on_complete`` + listed under ``"completions"``), occupancy,
         probe-dedup, tier and estimator-health metrics for this step.
         ``queue_depth`` is the server's admission backlog, recorded into the
-        device-resident queue gauge (traced data — never a recompile).
+        host-side queue histogram.
 
         Fault-injection order matters: the injector fires FIRST (a raised
         ``FaultError`` leaves the table unadvanced — the server retries the
         step), then the digest verify/restore cadence runs so a corrupted
         retrieval state is repaired BEFORE the compiled step consumes it.
 
-        Timing: ``wall_device_s`` covers dispatch + compiled step + the
-        outs readback; ``wall_host_s`` is everything else (injector, state
-        lookups, completion bookkeeping, ``on_token``/``on_complete``
-        callbacks); ``wall_s`` is their sum. The raw ``t_*`` perf_counter
-        stamps ride along for the span tracer."""
-        t0 = time.perf_counter()
+        Spans (``obs.tracing``; id = the step's index): ``sched.step``,
+        with the children ``sched.prep`` (injector, verify, tier state,
+        inputs), ``sched.dispatch`` (the compiled step's call, and the
+        outputs' copies to the host queued behind it), ``sched.wait``
+        (until the outputs are ready), ``sched.readback`` (until they are
+        host arrays) and ``sched.emit`` (the per-lane loop, the callbacks
+        and the record). The record's ``t_*`` stamps are theirs:
+        ``t_start`` starts ``sched.step``, ``t_dispatch`` starts
+        ``sched.dispatch``, ``t_device_done`` ends ``sched.readback`` and
+        ``t_done`` ends ``sched.step``. ``wall_device_s`` covers dispatch
+        to readback; ``wall_host_s`` is the rest of ``wall_s``."""
+        with tracing.Span(self.spans, "sched.step", self.steps_done) as sp:
+            with sp.child("sched.prep"):
+                call, restored = self._step_inputs()
+            with sp.child("sched.dispatch") as dispatch:
+                self.table, self.metrics_state, out = call()
+                self.steps_done += 1
+                for leaf in jax.tree.leaves(out):
+                    leaf.copy_to_host_async()
+            with sp.child("sched.wait"):
+                jax.block_until_ready(out)
+            with sp.child("sched.readback") as readback:
+                out = {k: np.asarray(v) for k, v in out.items()}
+            with sp.child("sched.emit"):
+                rec = self._emit(out, readback.end, restored)
+                self.step_hist.observe(
+                    max(queue_depth, 0),
+                    (readback.end - dispatch.start) * 1e3, self.tier)
+        rec.update(wall_s=sp.end - sp.start,
+                   wall_device_s=readback.end - dispatch.start,
+                   wall_host_s=(dispatch.start - sp.start)
+                   + (sp.end - readback.end),
+                   t_start=sp.start, t_dispatch=dispatch.start,
+                   t_device_done=readback.end, t_done=sp.end)
+        return rec
+
+    def _step_inputs(self):
+        """The host work before the compiled step: the fault hooks, the
+        verify cadence and the step's arguments. Returns the call to make
+        and whether the index was restored."""
         if self.injector is not None:
             self.injector.on_step_begin(self)
         restored = False
@@ -1073,41 +1121,27 @@ class Scheduler:
             if fault_nan is not self._no_fault:
                 fault_nan = jax.device_put(fault_nan, self._lane_sh)
                 fault_inf = jax.device_put(fault_inf, self._lane_sh)
-        # observability scalars: traced data with a fixed pytree structure,
-        # so toggling the shadow cadence or a moving queue depth hits the
-        # same executable
+        # the shadow cadence rides as traced data with a fixed pytree
+        # structure, so toggling it hits the same executable
         do_shadow = bool(self.shadow_every
                          and self.steps_done % self.shadow_every == 0)
-        extras = {"queue_depth": jnp.int32(max(queue_depth, 0)),
-                  "last_ms": jnp.float32(self._last_step_ms),
-                  "last_tier": jnp.int32(TIER_IX[self._last_step_tier]),
-                  "do_shadow": jnp.bool_(do_shadow)}
-        t_dispatch = time.perf_counter()
-        if spec:
-            self.table, self.metrics_state, out = step_fn(
-                self.table, params, bstate, dstate, fault_nan, fault_inf,
-                self.metrics_state, extras)
-        else:
-            self.table, self.metrics_state, out = step_fn(
-                self.table, params, bstate, fault_nan, fault_inf,
-                self.metrics_state, extras)
-        self.steps_done += 1
-        out = jax.device_get(out)
-        now = time.perf_counter()
-        self._last_step_ms = (now - t_dispatch) * 1e3
-        self._last_step_tier = self.tier
+        extras = {"do_shadow": self._shadow_flags[do_shadow]}
+        args = (self.table, params, bstate) + ((dstate,) if spec else ()) \
+            + (fault_nan, fault_inf, self.metrics_state, extras)
+        return partial(step_fn, *args), restored
+
+    def _emit(self, out: dict, now: float, restored: bool) -> dict:
+        """Stream the step's host outputs to the lanes' requests (stamped
+        ``now``), finish the lanes that are done, and build the step's
+        record (``step`` adds the stamps)."""
+        spec = self.spec_k > 1
         # normalize to (S, k) position-major token matrices: the non-spec
         # step is the k = 1 column
-        if np.asarray(out["token"]).ndim == 1:
-            tok = np.asarray(out["token"])[:, None]
-            em = np.asarray(out["emitted"])[:, None]
-            lp = np.asarray(out["log_prob"])[:, None]
-            lz = np.asarray(out["log_z"])[:, None]
-        else:
-            tok = np.asarray(out["token"])
-            em = np.asarray(out["emitted"])
-            lp = np.asarray(out["log_prob"])
-            lz = np.asarray(out["log_z"])
+        tok, em, lp, lz = (out["token"], out["emitted"], out["log_prob"],
+                           out["log_z"])
+        if tok.ndim == 1:
+            tok, em, lp, lz = (tok[:, None], em[:, None], lp[:, None],
+                               lz[:, None])
         completions = []
         for s in range(self.n_slots):
             req = self._slot_req[s]
@@ -1148,14 +1182,8 @@ class Scheduler:
                 completions.append(acc)
                 if req.on_complete is not None:
                     req.on_complete(req, acc)
-        flags = np.asarray(out["health"])
-        t_done = time.perf_counter()
-        rec = {"wall_s": t_done - t0,
-               "wall_device_s": now - t_dispatch,
-               "wall_host_s": (t_dispatch - t0) + (t_done - now),
-               "t_start": t0, "t_dispatch": t_dispatch,
-               "t_device_done": now, "t_done": t_done,
-               "n_active": int(out["n_active"]),
+        flags = out["health"]
+        rec = {"n_active": int(out["n_active"]),
                "head_live": int(out["head_live"]),
                "occupancy": int(out["n_active"]) / self.n_slots,
                "completions": completions,
@@ -1171,27 +1199,30 @@ class Scheduler:
                    int((flags & HEALTH_NONFINITE_SCORE > 0).sum())}
         if spec:
             rec["spec_proposed"] = int(out["n_active"]) * self.spec_k
-            rec["spec_accepted"] = int(np.asarray(out["accepted"]).sum())
-            rec["draft_flagged"] = \
-                int(np.asarray(out["draft_flagged"]).sum())
+            rec["spec_accepted"] = int(out["accepted"].sum())
+            rec["draft_flagged"] = int(out["draft_flagged"].sum())
         return rec
 
     def harvest_metrics(self) -> dict:
         """ONE device->host read of the cumulative metric pytree (the obs
-        layer calls this on its harvest cadence; see obs.metrics.harvest).
-        Counters are monotone — harvesting never resets them."""
-        return harvest_metric_state(self.metrics_state, self.n_slots)
+        layer calls this on its harvest cadence; see obs.metrics.harvest),
+        with the host-side step histograms. Counters are monotone —
+        harvesting never resets them."""
+        h = harvest_metric_state(self.metrics_state, self.n_slots)
+        h.update(self.step_hist.harvest())
+        return h
 
     def reset_metrics(self) -> None:
-        """Zero the device metric state (between benchmark phases). The
-        fresh pytree has identical shapes/shardings, so the next step hits
+        """Zero the metric state and the step histograms (between
+        benchmark phases). The fresh pytree has identical
+        shapes/shardings, so the next step hits
         its existing executable — pinned under the mesh exactly like the
         init-time state."""
         self.metrics_state = init_metric_state()
         if self.mesh is not None:
             self.metrics_state = jax.device_put(self.metrics_state,
                                                 self._repl_sh)
-        self._last_step_ms = -1.0
+        self.step_hist = StepHistograms()
 
     def drain(self, reason: str = "server_stopped") -> List[Completion]:
         """Forcibly close out every in-flight lane host-side: each open

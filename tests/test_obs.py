@@ -27,7 +27,8 @@ import pytest
 from repro.configs import ServingConfig, reduced_config
 from repro.models import Model
 from repro.obs import (LATENCY_EDGES_MS, TIERS, MetricsRegistry,
-                       Observability, ObsConfig, TraceWriter, hist_quantile)
+                       Observability, ObsConfig, Span, SpanLog, TraceWriter,
+                       hist_quantile)
 from repro.serve import Engine, Request, Scheduler, Server, trace_arrivals
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -229,9 +230,8 @@ class TestShadowTelemetry:
         h = sched.harvest_metrics()
         counts = h["latency_hist_by_tier"]["mimps"]
         assert len(counts) == len(LATENCY_EDGES_MS) + 1
-        # the warm run records every step but the first (feed-forward: step
-        # N's device time lands in step N+1's histogram)
-        assert sum(counts) == h["steps"] - 1
+        # the host-side histogram records every step of the warm run
+        assert sum(counts) == h["steps"]
         cum = np.cumsum(counts)
         assert all(b >= a for a, b in zip(cum, cum[1:]))
         q = hist_quantile(np.asarray(counts), LATENCY_EDGES_MS, 0.99)
@@ -270,8 +270,12 @@ class TestTraceArtifacts:
         names = {e["name"] for e in events}
         # lifecycle spans + step phases + instants all present
         for want in ("enqueue", "queued", "replay", "decode", "request",
-                     "device_step:mimps", "host_step"):
+                     "sched.step", "sched.prep", "sched.dispatch",
+                     "sched.wait", "sched.readback", "sched.emit",
+                     "sched.admit"):
             assert want in names, want
+        assert {e["args"]["tier"] for e in events
+                if e["name"] == "sched.step"} == {"mimps"}
         for e in events:
             assert e["ph"] in ("X", "i", "C", "M")
             if e["ph"] == "X":
@@ -279,6 +283,12 @@ class TestTraceArtifacts:
         snap = json.loads((tmp_path / "snap.json").read_text())
         assert snap["serving_steps"] > 0
         assert snap["harvest"]["tokens_total"] > 0
+        # the host-side histograms keep their exposition names
+        assert "queue_depth_mean" in snap
+        assert any("mimps" in k.split("/")
+                   for k in snap["step_latency_ms_bucket"])
+        assert "repro_step_latency_ms_bucket{" in \
+            obs.registry.prometheus_text()
 
         from repro.launch import obs_report
         assert obs_report.main([str(path),
@@ -298,8 +308,8 @@ class TestTraceArtifacts:
         from repro.launch import obs_report
         trace = tmp_path / "t.jsonl"
         trace.write_text(json.dumps(
-            {"ph": "X", "name": "device_step:topk", "ts": 0, "dur": 1,
-             "pid": 1, "tid": 0}) + "\n")
+            {"ph": "X", "name": "sched.step", "ts": 0, "dur": 1,
+             "pid": 1, "tid": 0, "args": {"tier": "topk"}}) + "\n")
         snap = tmp_path / "s.json"
         snap.write_text(json.dumps(
             {"harvest": {"tokens_by_tier": {"mimps": 7},
@@ -347,6 +357,112 @@ class TestRegistry:
         # ctor names tid 0 ("scheduler") + the 4 events above
         assert len(lines) == w.events_written == 5
         assert all(json.loads(l)["pid"] == 1 for l in lines)
+
+
+PHASES = ("sched.prep", "sched.dispatch", "sched.wait", "sched.readback",
+          "sched.emit")
+
+
+class TestSpanLog:
+    def test_bounded_keeps_newest(self):
+        log = SpanLog(capacity=4)
+        for i in range(10):
+            log.add("s", float(i), float(i) + 0.5, id=i)
+        assert len(log) == 4 and log.added == 10
+        assert [r.id for r in log.records()] == [6, 7, 8, 9]
+        # a cursor older than the log's reach gets what is still held
+        recs, mark = log.since(3)
+        assert [r.id for r in recs] == [6, 7, 8, 9] and mark == 10
+        assert log.since(mark) == ([], 10)
+
+    def test_parent_and_id_links(self):
+        log = SpanLog()
+        with Span(log, "outer", 7) as sp:
+            with sp.child("inner") as c:
+                pass
+        inner, outer = log.records()
+        assert (inner.name, inner.parent, inner.id) == ("inner", "outer", 7)
+        assert (outer.name, outer.parent, outer.id) == ("outer", None, 7)
+        assert outer.start <= inner.start <= inner.end <= outer.end
+        assert (c.start, c.end) == (inner.start, inner.end)
+
+    def test_off_when_none(self):
+        with Span(None, "s", 1) as sp:
+            with sp.child("c") as c:
+                pass
+        # stamps are still taken; nothing is recorded anywhere
+        assert sp.start <= c.start <= c.end <= sp.end
+
+    def test_tracewriter_writes_span_log(self, tmp_path):
+        log = SpanLog()
+        log.add("sched.step", 1.0, 2.0, id=3)
+        log.add("sched.admit", 1.5, 1.75, id=11)
+        w = TraceWriter(str(tmp_path / "s.jsonl"))
+        w.write_spans(log.records(), {"sched.step": {"tier": "mimps"}})
+        w.close()
+        ev = [json.loads(l) for l in
+              (tmp_path / "s.jsonl").read_text().splitlines()][1:]
+        assert [(e["name"], e["args"]["id"]) for e in ev] == [
+            ("sched.step", 3), ("sched.admit", 11)]
+        assert ev[0]["args"]["tier"] == "mimps"
+        assert ev[1]["dur"] == pytest.approx(0.25e6)
+
+
+class TestSchedulerSpans:
+    def test_step_has_each_phase_once_and_admit_carries_req_id(
+            self, served, rng):
+        eng, cfg = served
+        log = SpanLog()
+        sched = Scheduler(eng, n_slots=3, key=rng, spans=log)
+        req = _requests(cfg, rng, n=1)[0]
+        sched.admit(req)
+        rec = sched.step()
+        recs = log.records()
+        assert [r.id for r in recs if r.name == "sched.admit"] == \
+            [req.req_id]
+        (step,) = [r for r in recs if r.name == "sched.step"]
+        assert (step.start, step.end) == (rec["t_start"], rec["t_done"])
+        kids = [r for r in recs if r.parent == "sched.step"]
+        assert tuple(k.name for k in kids) == PHASES
+        assert all(k.id == step.id == 0 for k in kids)
+        assert step.start <= kids[0].start
+        assert kids[-1].end <= step.end
+        assert all(a.end <= b.start for a, b in zip(kids, kids[1:]))
+        assert rec["t_dispatch"] == kids[1].start
+        assert rec["t_device_done"] == kids[3].end
+
+    def test_no_step_or_admission_dispatches_a_conversion(
+            self, served, rng, monkeypatch):
+        """Every host value reaches the compiled step and admission as an
+        argument of the call: nothing is converted on the device first."""
+        from jax._src.lax import lax as lax_internal
+        eng, cfg = served
+        sched = Scheduler(eng, n_slots=3, key=rng, spans=None)
+        reqs = _requests(cfg, rng, n=2)
+        sched.admit(reqs[0])
+        sched.step()                      # warm: the step compiles here
+        sched.shadow_every = 2            # both shadow flags get used
+        traces = (sched.step_traces, sched.admit_traces)
+        prim = lax_internal.convert_element_type_p
+        binds = []
+        orig = prim.bind
+        monkeypatch.setattr(prim, "bind",
+                            lambda *a, **k: binds.append(1) or orig(*a, **k),
+                            raising=False)
+        sched.admit(reqs[1])
+        for q in range(3):
+            sched.step(queue_depth=q)
+        assert binds == []
+        assert (sched.step_traces, sched.admit_traces) == traces
+
+    def test_lowered_step_carries_named_scopes(self, served, rng):
+        eng, cfg = served
+        sched = Scheduler(eng, n_slots=3, key=rng, spans=None)
+        call, _ = sched._step_inputs()
+        text = call.func.lower(*call.args).as_text(debug_info=True)
+        for scope in ("trunk", "attention", "mlp", "kv_cache",
+                      "output_layer"):
+            assert f"{scope}/" in text, scope
 
 
 MESH_OBS_SNIPPET = r"""
