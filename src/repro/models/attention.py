@@ -134,15 +134,27 @@ class KVCache(NamedTuple):
     v: jax.Array
 
 
-def decode_self_attention(p: Params, x: jax.Array, cache: KVCache, pos,
-                          cfg, *, window: int = 0):
-    """Single-token decode. x (B, 1, d); pos: absolute position — a scalar
-    shared by the batch (generate()'s lock-step loop) or an (B,) vector of
-    per-stream positions (the continuous-batching slot table, where each
-    lane is at a different depth of its own request).
+def ring_slot(pos, window: int = 0):
+    """Cache row that the token at absolute position `pos` occupies: `pos`
+    itself, or `pos % window` in a sliding-window layer's ring buffer."""
+    return pos % window if window else pos
 
-    Returns (out (B, 1, d), updated cache). For sliding-window layers the
-    cache is a ring buffer of length `window`.
+
+def decode_attend(p: Params, x: jax.Array, cache: KVCache, pos, cfg, *,
+                  window: int = 0):
+    """Single-token decode attention that reads `cache` and does not write
+    it. x (B, 1, d); pos: absolute position — a scalar shared by the batch
+    (generate()'s lock-step loop) or an (B,) vector of per-stream positions
+    (the continuous-batching slot table, where each lane is at a different
+    depth of its own request).
+
+    The query scores the cached positions before `pos` and the token's own
+    k, in the cache's dtype, under one softmax: the same set the cache holds
+    once the token is written. In a sliding-window ring the slot the token
+    will overwrite (position `pos - window`) is left out.
+
+    Returns (out (B, 1, d), (k, v)), k and v (B, 1, n_kv, Dh) in the cache's
+    dtype: the rows to write at `ring_slot(pos, window)`.
     """
     q, k, v = _project_qkv(p, x, x, cfg)             # q (B,1,Kv,G,Dh)
     pos = jnp.asarray(pos)
@@ -151,39 +163,75 @@ def decode_self_attention(p: Params, x: jax.Array, cache: KVCache, pos,
     b = x.shape[0]
     qf = apply_rope(q.reshape(b, 1, -1, q.shape[-1]), posv, cfg.rope_theta)
     q = qf.reshape(q.shape)
-    k = apply_rope(k, posv, cfg.rope_theta)
+    k = apply_rope(k, posv, cfg.rope_theta).astype(cache.k.dtype)
+    v = v.astype(cache.v.dtype)
     s_max = cache.k.shape[1]
-    slot = (pos % window) if window else pos
-    with jax.named_scope("kv_cache"):
-        new_k = _dyn_update(cache.k, k, slot)
-        new_v = _dyn_update(cache.v, v, slot)
-    valid = jnp.minimum(pos + 1, s_max)
-    scale = cfg.resolved_head_dim ** -0.5
-    s = jnp.einsum("bqkgd,bskd->bkgqs", q, new_k,
-                   preferred_element_type=jnp.float32) * scale
+    # the write clamps its row into the cache, so the mask does too
+    slot = jnp.clip(ring_slot(pos, window), 0, s_max - 1)
     kv_idx = jnp.arange(s_max)
-    if per_slot:
-        mask = kv_idx[None, :] < valid[:, None]      # (B, s_max)
-        s = jnp.where(mask[:, None, None, None, :], s, NEG)
-    else:
-        mask = kv_idx < valid
-        s = jnp.where(mask[None, None, None, None], s, NEG)
-    a = jax.nn.softmax(s, axis=-1).astype(new_v.dtype)
-    o = jnp.einsum("bkgqs,bskd->bqkgd", a, new_v)
-    out = o.reshape(*x.shape[:-1], -1) @ p["wo"]
+    posb = pos[:, None] if per_slot else pos[None, None]
+    slotb = slot[:, None] if per_slot else slot[None, None]
+    mask = (kv_idx < jnp.minimum(posb, s_max)) & (kv_idx != slotb)
+    scale = cfg.resolved_head_dim ** -0.5
+    s_c = jnp.einsum("bqkgd,bskd->bkgqs", q, cache.k,
+                     preferred_element_type=jnp.float32) * scale
+    s_c = jnp.where(mask[:, None, None, None, :], s_c, NEG)
+    s_t = jnp.einsum("bqkgd,bqkd->bkgq", q, k,
+                     preferred_element_type=jnp.float32)[..., None] * scale
+    m = jnp.maximum(jnp.max(s_c, axis=-1, keepdims=True), s_t)
+    e_c = jnp.exp(s_c - m)
+    e_t = jnp.exp(s_t - m)
+    den = jnp.sum(e_c, axis=-1, keepdims=True) + e_t
+    a_c = (e_c / den).astype(v.dtype)
+    a_t = (e_t / den).astype(v.dtype)
+    o = (jnp.einsum("bkgqs,bskd->bqkgd", a_c, cache.v,
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("bkgqs,bqkd->bqkgd", a_t, v,
+                      preferred_element_type=jnp.float32))
+    out = o.astype(v.dtype).reshape(*x.shape[:-1], -1) @ p["wo"]
+    return out, (k, v)
+
+
+def decode_self_attention(p: Params, x: jax.Array, cache: KVCache, pos,
+                          cfg, *, window: int = 0):
+    """Single-token decode for one layer: `decode_attend`, then the token's
+    rows written into this layer's cache (`write_token_rows`). The grouped
+    stacks (local/global, vision, hybrid) decode through it, each layer's
+    cache a scan input and its updated cache a scan output. The homogeneous
+    stack (`Model.decode_step`) calls `decode_attend` in its layer scan
+    instead, reading every layer's cache in place, and writes the rows of
+    all layers after the scan.
+
+    Returns (out (B, 1, d), updated cache). For sliding-window layers the
+    cache is a ring buffer of length `window`.
+    """
+    out, (k, v) = decode_attend(p, x, cache, pos, cfg, window=window)
+    slot = ring_slot(pos, window)
+    with jax.named_scope("kv_cache"):
+        new_k = write_token_rows(cache.k, k[:, 0], slot)
+        new_v = write_token_rows(cache.v, v[:, 0], slot)
     return out, KVCache(k=new_k, v=new_v)
 
 
-def _dyn_update(buf: jax.Array, row: jax.Array, slot) -> jax.Array:
-    """Write one token's KV at `slot` — scalar (whole batch at the same
-    position) or (B,) (each lane at its own position, vmapped)."""
+def write_token_rows(leaf: jax.Array, rows: jax.Array, slot) -> jax.Array:
+    """Write one token's rows of every stacked layer into `leaf` (*stack, B,
+    S, n_kv, Dh) at position `slot`: rows (*stack, B, n_kv, Dh); slot a
+    scalar (one update for the whole batch) or (B,) (one update per lane;
+    the lane count is static). Each is a `dynamic_update_slice` that XLA
+    does in place on a donated cache: a vmapped or `.at[].set` write lowers
+    to a general scatter, for which XLA relays out the whole cache."""
     slot = jnp.asarray(slot, jnp.int32)
-    row = row.astype(buf.dtype)
+    rows = jnp.expand_dims(rows.astype(leaf.dtype), -3)   # (*stack,B,1,..)
+    starts = [jnp.int32(0)] * leaf.ndim
     if slot.ndim == 0:
-        return jax.lax.dynamic_update_slice(buf, row, (0, slot, 0, 0))
-    return jax.vmap(
-        lambda b, r, s: jax.lax.dynamic_update_slice(b, r, (s, 0, 0))
-    )(buf, row, slot)
+        starts[-3] = slot
+        return jax.lax.dynamic_update_slice(leaf, rows, starts)
+    for lane in range(leaf.shape[-4]):
+        starts[-4] = jnp.int32(lane)
+        starts[-3] = slot[lane]
+        row = jax.lax.slice_in_dim(rows, lane, lane + 1, axis=rows.ndim - 4)
+        leaf = jax.lax.dynamic_update_slice(leaf, row, starts)
+    return leaf
 
 
 # -- lane-window KV block ops (serve.prefix_cache) ---------------------------
@@ -215,7 +263,7 @@ def write_lane_window(leaf: jax.Array, rows: jax.Array, lane,
                       start) -> jax.Array:
     """Multi-token append: write `rows` (*stack, 1, length, n_kv, Dh) into
     one lane of `leaf` at positions [start, start+length). The per-token
-    `_dyn_update` generalized to a contiguous window — the prefix-cache
+    `write_token_rows` generalized to a contiguous window — the prefix-cache
     copy lands L tokens of KV in one dynamic_update_slice instead of L
     replay steps."""
     nd = leaf.ndim

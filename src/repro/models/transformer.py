@@ -10,8 +10,11 @@ axis (HLO size O(1) in depth — see DESIGN.md SS7). Heterogeneous patterns use
                 weight copy, closure) + tail of 3 mamba
   others      : one homogeneous scanned stack
 
-Decode states are pytrees stacked the same way as their stacks, so the same
-scan drives the cached path.
+Decode states are pytrees stacked the same way as their stacks. The
+homogeneous stack's scan reads each layer's KV cache in place (a scan
+input only) and emits the token's K/V rows, which are written into the
+cache once, after the scan; the grouped stacks thread each layer's cache
+through their scans as input and output.
 """
 from __future__ import annotations
 
@@ -22,8 +25,9 @@ import jax
 import jax.numpy as jnp
 
 from ..configs.base import ModelConfig
-from .attention import (KVCache, cross_attention, decode_self_attention,
-                        init_attention, self_attention)
+from .attention import (KVCache, cross_attention, decode_attend,
+                        decode_self_attention, init_attention, ring_slot,
+                        self_attention, write_token_rows)
 from .layers import (embed, init_embedding, init_mlp, init_rmsnorm, mlp,
                      rmsnorm, _dense_init)
 from .mamba import init_mamba_block, init_mamba_state, mamba_block
@@ -83,9 +87,12 @@ def tblock_fwd(p: Params, x, cfg, *, kind="dense", window=0):
 
 
 def tblock_decode(p: Params, x, cache: KVCache, pos, cfg, *, kind="dense",
-                  window=0):
+                  window=0, attend=decode_self_attention):
+    """One block of single-token decode. Returns (x, what `attend` returns
+    beside its output): the updated cache, or the token's (k, v) rows with
+    `attend=decode_attend`."""
     with jax.named_scope("attention"):
-        h, cache = decode_self_attention(
+        h, cache = attend(
             p["attn"], rmsnorm(p["ln1"], x, cfg.norm_eps), cache, pos, cfg,
             window=window)
     x = x + h
@@ -392,13 +399,22 @@ class Model:
             kind = "moe" if fam == "moe" else "dense"
             win = cfg.sliding_window
 
+            # the scan only reads the cache and emits the token's rows
+            # (L, B, Kv, Dh); a cache carried through it, or stacked as its
+            # output, is sliced out and written back whole every step
             def f(x_, xs):
                 px, st = xs
-                x2, c = tblock_decode(px, x_, as_cache(st), pos, cfg,
-                                      kind=kind, window=win)
-                return x2, {"k": c.k, "v": c.v}
-            x, new_kv = jax.lax.scan(f, x, (p["blocks"], state["kv"]))
-            new_state = {"kv": new_kv}
+                x2, (k, v) = tblock_decode(px, x_, as_cache(st), pos, cfg,
+                                           kind=kind, window=win,
+                                           attend=decode_attend)
+                return x2, (k[:, 0], v[:, 0])
+            x, (k_rows, v_rows) = jax.lax.scan(
+                f, x, (p["blocks"], state["kv"]))
+            slot = ring_slot(pos, win)
+            with jax.named_scope("kv_cache"):
+                new_state = {"kv": {
+                    "k": write_token_rows(state["kv"]["k"], k_rows, slot),
+                    "v": write_token_rows(state["kv"]["v"], v_rows, slot)}}
         elif cfg.local_global_ratio:
             g, r, tail = _gemma_plan(cfg)
             w = cfg.sliding_window

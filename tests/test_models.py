@@ -163,3 +163,114 @@ class TestTrainSubstrate:
         x0, y0 = next(it)
         assert x0.shape == (4, 16) and y0.shape == (4, 16)
         np.testing.assert_array_equal(x0[:, 1:], y0[:, :-1])
+
+
+def _decode_case(case):
+    """(config, scalar_pos) for one decode-against-forward case."""
+    if case == "gqa":
+        cfg = reduced_config("mistral-nemo-12b")
+        assert cfg.n_heads // cfg.n_kv_heads > 1
+    elif case == "moe":
+        cfg = reduced_config("deepseek-moe-16b")
+        # room for every token, so the forward over whole sequences drops
+        # none where one-token decode drops none either; float32, because
+        # top-k routing is discontinuous: in bf16 a near-tie of two experts'
+        # router logits flips on the rounding difference between the
+        # forward's and the decode's attention sums
+        cfg = dataclasses.replace(cfg, dtype="float32",
+                                  moe=dataclasses.replace(
+                                      cfg.moe, capacity_factor=float(
+                                          cfg.moe.n_experts)))
+    elif case == "local_global":
+        # the grouped stack, whose layers write their own rows in its
+        # scans; a window short enough that the local layers' rings wrap
+        cfg = dataclasses.replace(reduced_config("gemma3-4b"),
+                                  sliding_window=4)
+        assert cfg.local_global_ratio
+    else:
+        cfg = reduced_config("qwen1.5-4b")
+        assert cfg.qkv_bias and cfg.n_heads == cfg.n_kv_heads
+        if case == "window":
+            cfg = dataclasses.replace(cfg, sliding_window=4)
+    return cfg, case == "scalar_pos"
+
+
+class TestDecodeMatchesForward:
+    """Cached decode, one token a step, gives the hidden state of the full
+    forward over the same prefix, for every lane at its own depth."""
+
+    STEPS, LANES = 11, 3
+
+    @pytest.mark.parametrize("case", ["mha_bias", "gqa", "moe", "window",
+                                      "scalar_pos", "local_global"])
+    def test_decode_hidden_matches_forward(self, case, rng):
+        cfg, scalar = _decode_case(case)
+        m = Model(cfg)
+        p = m.init(rng)
+        n, b = self.STEPS, self.LANES
+        # lane l decodes sequence 2l from position 0; at step restart[l] it
+        # restarts at position 0 on sequence 2l+1, over the stale rows of
+        # the first (all lanes together when `pos` is one scalar)
+        restart = [5] * b if scalar else [n, 3, 6]
+        seqs = jax.random.randint(jax.random.fold_in(rng, 7), (2 * b, n), 0,
+                                  cfg.vocab)
+        want, _ = jax.jit(m.forward)(p, seqs)          # (2b, n, d)
+        step = jax.jit(m.decode_step)
+        state = m.init_decode_state(b, 16)
+        if case == "window":
+            assert state["kv"]["k"].shape[2] == cfg.sliding_window < n
+        elif case == "local_global":
+            assert state["local"]["k"].shape[3] == cfg.sliding_window < n
+        errs = []
+        for t in range(n):
+            seq = [2 * l + (t >= restart[l]) for l in range(b)]
+            pos = [t - restart[l] if t >= restart[l] else t for l in range(b)]
+            tok = jnp.asarray([seqs[s, q] for s, q in zip(seq, pos)],
+                              jnp.int32)
+            pos_in = jnp.int32(pos[0]) if scalar else jnp.asarray(
+                pos, jnp.int32)
+            h, state = step(p, state, tok, pos_in)
+            ref = jnp.stack([want[s, q] for s, q in zip(seq, pos)])
+            errs.append(np.abs(np.asarray(h, np.float32)
+                               - np.asarray(ref, np.float32)))
+        errs = np.stack(errs)                          # (n, b, d); NaN fails
+        assert np.all(errs < 0.1), np.nanmax(errs)
+
+
+class TestDecodeCacheInPlace:
+    def test_layer_scan_does_not_output_the_cache(self, rng):
+        """The homogeneous stack's layer scan takes the KV cache as a
+        read-only input and emits only the token's rows; the cache is
+        written after the scan. A scan that returns the cache (or anything
+        of its shape) copies it every step."""
+        cfg = reduced_config("qwen1.5-4b")
+        m = Model(cfg)
+        p = m.init(rng)
+        state = m.init_decode_state(3, 16)
+        cache_shape = state["kv"]["k"].shape
+        tok = jnp.zeros((3,), jnp.int32)
+        pos = jnp.asarray([4, 0, 9], jnp.int32)
+        closed = jax.make_jaxpr(m.decode_step)(p, state, tok, pos)
+        n_params = len(jax.tree.leaves(p))
+        cache_vars = closed.jaxpr.invars[n_params:n_params + 2]
+        assert all(v.aval.shape == cache_shape for v in cache_vars)
+
+        def scans(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "scan":
+                    yield eqn
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from scans(sub)
+
+        found = list(scans(closed.jaxpr))
+        layer = [e for e in found
+                 if any(v in e.invars for v in cache_vars)]
+        assert len(layer) == 1, "the layer scan reads the cache directly"
+        for e in found:
+            assert not any(v.aval.shape == cache_shape for v in e.outvars)
+        # the new cache comes from the in-place row writes, not from a scan
+        made_by = {v: e for e in closed.jaxpr.eqns for v in e.outvars}
+        new_cache = closed.jaxpr.outvars[1:]
+        assert [v.aval.shape for v in new_cache] == [cache_shape] * 2
+        assert all(made_by[v].primitive.name == "dynamic_update_slice"
+                   for v in new_cache)
